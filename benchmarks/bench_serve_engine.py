@@ -31,10 +31,6 @@ plus the paged-cache mix (DESIGN.md §10):
               agree with ``ServeEngine.stats()``, and the paged streams
               are checked token-identical to the dense engine's.
 
-``--smoke`` additionally emits the tp=2-vs-tp=1 decode tok/s row (the
-ROADMAP bench-trajectory item) by re-running the burst mix at both tp
-sizes in a child process with 2 fake CPU devices.
-
 Rows land in experiments/bench/serve_engine.csv. Run standalone
 (``python -m benchmarks.bench_serve_engine [--use-kernel]
 [--kv-quant fxp8]``) or via ``benchmarks.run``.
@@ -43,7 +39,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import os
 
 import jax
 import numpy as np
@@ -275,82 +270,6 @@ def run_sharedprefix(cfg, params, kv_spec, use_kernel: bool, sz: Sizes,
     return [row], claims
 
 
-_TP_SMOKE_CODE = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import sys; sys.path.insert(0, "src")
-import numpy as np, jax
-from repro.configs import ARCHS, RunConfig, smoke
-from repro.launch.engine import Request, SamplingParams, ServeEngine
-from repro.launch.mesh import make_tp_mesh
-from repro.nn.models import build_model
-
-cfg = smoke(ARCHS["yi-9b"])
-rcfg = RunConfig(remat="none", activation_dtype="f32")
-params = build_model(cfg, rcfg).init(jax.random.PRNGKey(0))
-def reqs():
-    return [Request(rid=i,
-                    prompt=np.random.RandomState(i).randint(0, cfg.vocab_size, 8),
-                    max_new=6, sampling=SamplingParams())
-            for i in range(4)]
-for tp in (1, 2):
-    mesh = make_tp_mesh(tp) if tp > 1 else None
-    eng = ServeEngine(build_model(cfg, rcfg, mesh=mesh), params,
-                      n_slots=2, max_len=24, chunk=4)
-    eng.run(reqs())                       # warmup: compile outside timing
-    eng.prefill_time = eng.decode_time = 0.0
-    eng.decode_steps = 0
-    eng.clock = 0.0
-    warm = eng.stats()["generated_tokens"]
-    warm_sampled = eng.n_prefill_sampled
-    done = eng.run([Request(rid=100 + r.rid, prompt=r.prompt,
-                            max_new=r.max_new, sampling=r.sampling)
-                    for r in reqs()])
-    st = eng.stats()
-    n_dec = (st["generated_tokens"] - warm) - (eng.n_prefill_sampled
-                                               - warm_sampled)
-    print(f"TPROW,{tp},{n_dec / max(st['decode_time_s'], 1e-9):.2f}")
-"""
-
-
-def run_tp_smoke():
-    """tp=2 vs tp=1 decode tok/s (the ROADMAP bench-trajectory item).
-
-    Runs in a child process with 2 fake CPU devices so the row exists even
-    on single-device CI; on fake devices the ratio measures overhead, not
-    speedup — the row's value is the *trajectory* (it fails loudly when TP
-    serving bit-rots, and becomes a real comparison on multi-core
-    runners). Returns (rows, claims).
-    """
-    import subprocess
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r = subprocess.run([sys.executable, "-c", _TP_SMOKE_CODE],
-                       capture_output=True, text=True, cwd=root,
-                       timeout=1200)
-    if r.returncode != 0:
-        raise RuntimeError(f"tp smoke subprocess failed:\n{r.stderr[-3000:]}")
-    rates = {}
-    for line in r.stdout.splitlines():
-        if line.startswith("TPROW,"):
-            _, tp, rate = line.split(",")
-            rates[int(tp)] = float(rate)
-    if sorted(rates) != [1, 2]:
-        raise RuntimeError(f"tp smoke emitted {rates}, expected tp 1 and 2")
-    rows = [{
-        "mix": "tp-decode", "arch": ARCH, "quant": "none",
-        "use_kernel": False, "slots": 2, "requests": 4,
-        "prompt_len": 8, "gen": 6,
-        "kv_variant": f"tp={tp}",
-        "decode_tok_per_s": rate,
-    } for tp, rate in sorted(rates.items())]
-    claims = {
-        "tp2_vs_tp1_decode_ratio": round(rates[2] / max(rates[1], 1e-9), 3),
-    }
-    return rows, claims
-
-
 def run(use_kernel: bool = False, quant: str = "pofx8",
         kv_quant: str = "fxp8", smoke: bool = False):
     sz = SMOKE if smoke else FULL
@@ -412,12 +331,6 @@ def run(use_kernel: bool = False, quant: str = "pofx8",
                                           sz, page_size=2 if smoke else 8)
     rows += sp_rows
     claims.update(sp_claims)
-    if smoke:
-        # the ROADMAP bench-trajectory item: a tp=2-vs-tp=1 decode tok/s
-        # datapoint, emitted from --smoke so the CI bit-rot run carries it
-        tp_rows, tp_claims = run_tp_smoke()
-        rows += tp_rows
-        claims.update(tp_claims)
     write_csv("serve_engine", rows)
     return rows, claims
 

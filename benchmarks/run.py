@@ -34,6 +34,7 @@ BENCHES = [
 
 def main(argv=None) -> int:
     from repro.core.policy import add_policy_arg, parse_spec
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
@@ -46,6 +47,7 @@ def main(argv=None) -> int:
                    extra_help="extra spec strings appended to the "
                               "format-sweeping benches")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     only = [s for s in args.only.split(",") if s]
     extra_specs = tuple(s for s in args.quant.split(",") if s)
     for s in extra_specs:

@@ -33,6 +33,29 @@ __all__ = [
     "NAR",
 ]
 
+# Tables up to this many entries are searched and read with elementwise
+# compares and selects instead of gathers. On a TPU v5e a gather of one value
+# per element from a small table costs several ns per element (decoding a
+# 262M-code table that way took about 2 s), and the searchsorted encode makes
+# about ten per element: minutes for a 9B model. Selects run at VPU speed and
+# give the same values.
+_SELECT_MAX = 256
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _select_chain(idx, table: tuple):
+    out = jnp.full(idx.shape, table[0], jnp.float32)
+    for j, v in enumerate(table[1:], 1):
+        out = jnp.where(idx == j, v, out)
+    return out
+
+
+def _table_lookup(table: np.ndarray, idx) -> jax.Array:
+    """``table[idx]`` for a float32 host table; idx must lie in range."""
+    if table.shape[0] > _SELECT_MAX:
+        return jnp.take(jnp.asarray(table), idx, axis=0)
+    return _select_chain(idx, tuple(table.tolist()))
+
 
 def NAR(N: int) -> int:
     """The Not-a-Real code for an N-bit posit (1 followed by zeros)."""
@@ -142,11 +165,18 @@ def posit_min_pos(N: int, ES: int) -> float:
 def _encode_impl(x, N: int, ES: int, xp, table, allow_zero: bool):
     a = xp.abs(x)
     L = 1 << (N - 1)
-    idx = xp.clip(xp.searchsorted(table, a), 0, L - 1)
+    if xp is np:
+        idx = np.searchsorted(table, a)
+        take = lambda i: table[i]
+    else:
+        method = "compare_all" if L <= _SELECT_MAX else "scan"
+        idx = jnp.searchsorted(jnp.asarray(table), a, method=method)
+        take = lambda i: _table_lookup(table, i)
+    idx = xp.clip(idx, 0, L - 1)
     lo = xp.clip(idx - 1, 0, L - 1)
     hi = idx
-    dlo = a - table[lo]
-    dhi = table[hi] - a
+    dlo = a - take(lo)
+    dhi = take(hi) - a
     # Nearest; ties -> even code (one of two consecutive codes is even).
     take_lo = (dlo < dhi) | ((dlo == dhi) & (lo % 2 == 0))
     code = xp.where(take_lo, lo, hi).astype(xp.int32)
@@ -171,7 +201,7 @@ def posit_encode_np(x, N: int, ES: int, allow_zero: bool = True) -> np.ndarray:
 def posit_encode(x, N: int, ES: int, allow_zero: bool = True) -> jax.Array:
     """jnp encode; table is closed over as a constant (2^(N-1) floats)."""
     _check_config(N, ES)
-    table = jnp.asarray(posit_value_table(N, ES), dtype=jnp.float32)
+    table = posit_value_table(N, ES).astype(np.float32)
     return _encode_impl(jnp.asarray(x, dtype=jnp.float32), N, ES, jnp, table, allow_zero)
 
 

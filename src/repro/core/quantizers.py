@@ -31,7 +31,7 @@ import numpy as np
 from . import fxp as _fxp
 from . import normalized_posit as _np_
 from . import posit as _posit
-from .pofx import pofx_norm_lut
+from .pofx import pofx_normalized
 
 __all__ = ["QuantSpec", "QuantizedTensor", "quantize", "dequantize",
            "storage_bits", "validate_kv_spec", "kv_code_dtype", "kv_quantize",
@@ -107,30 +107,51 @@ def _as_f32(x):
     return jnp.asarray(x, dtype=jnp.float32)
 
 
-def quantize(w, spec: QuantSpec, axis: Optional[int] = None) -> QuantizedTensor:
-    """Quantize a float array into the storage format named by ``spec``."""
-    w = _as_f32(w)
-    if spec.kind in ("fp32", "bf16"):
-        dt = jnp.float32 if spec.kind == "fp32" else jnp.bfloat16
-        one = jnp.ones((1,) * max(w.ndim, 1), jnp.float32)
-        return QuantizedTensor(w.astype(dt), one, spec)
-    if axis is None and spec.scale_mode.startswith("channel"):
-        axis = -1  # convention: last axis is the output-channel axis
-    scale = _fxp.compute_scale(w, spec.scale_mode, axis)
-    wn = w / scale
+# Leaves larger than this many elements encode a block of rows at a time:
+# the encoder's full-size int32/f32 temporaries would otherwise cost several
+# times the leaf (about 3.5 GB for the 0.26 GB of yi-9b embedding codes).
+_ENCODE_BLOCK = 1 << 22
+
+
+def _encode(wn, spec: QuantSpec) -> jax.Array:
+    """Normalized values (in [-1, 1]) -> storage codes, elementwise."""
     if spec.kind == "fxp":
         codes = _fxp.fxp_quantize(wn, spec.M, spec.F)
-        dt = jnp.int8 if spec.M <= 8 else jnp.int32
-        return QuantizedTensor(codes.astype(dt), scale, spec)
+        return codes.astype(jnp.int8 if spec.M <= 8 else jnp.int32)
     if spec.kind == "posit":
         codes = _posit.posit_encode(wn, spec.N, spec.ES)
-        return QuantizedTensor(codes.astype(spec.code_dtype()), scale, spec)
+        return codes.astype(spec.code_dtype())
     # pofx: optionally pre-round through the FxP grid (Table 5's good path),
     # then encode onto the normalized posit lattice.
     if spec.path == "via_fxp":
         wn = _fxp.fxp_dequantize(_fxp.fxp_quantize(wn, spec.M, spec.M - 1), spec.M - 1)
-    codes = _np_.norm_encode(wn, spec.N, spec.ES)
-    return QuantizedTensor(codes.astype(spec.code_dtype()), scale, spec)
+    return _np_.norm_encode(wn, spec.N, spec.ES).astype(spec.code_dtype())
+
+
+def quantize(w, spec: QuantSpec, axis: Optional[int] = None) -> QuantizedTensor:
+    """Quantize a float array into the storage format named by ``spec``."""
+    w = jnp.asarray(w)
+    if spec.kind in ("fp32", "bf16"):
+        dt = jnp.float32 if spec.kind == "fp32" else jnp.bfloat16
+        one = jnp.ones((1,) * max(w.ndim, 1), jnp.float32)
+        return QuantizedTensor(_as_f32(w).astype(dt), one, spec)
+    if axis is None and spec.scale_mode.startswith("channel"):
+        axis = -1  # convention: last axis is the output-channel axis
+    scale = _fxp.compute_scale(_as_f32(w), spec.scale_mode, axis)
+    if w.ndim < 2 or w.size <= _ENCODE_BLOCK or scale.shape[0] != 1:
+        return QuantizedTensor(_encode(_as_f32(w) / scale, spec), scale, spec)
+    rows = max(1, _ENCODE_BLOCK // (w.size // w.shape[0]))
+    codes = jax.lax.map(lambda row: _encode(_as_f32(row) / scale[0], spec),
+                        w, batch_size=rows)
+    return QuantizedTensor(codes, scale, spec)
+
+
+def _pofx_fxp_codes(codes, spec: QuantSpec) -> jax.Array:
+    """Stored pofx codes -> FxP(M, M-1) codes by the bit-level algorithm,
+    elementwise: the values of ``pofx_norm_lut`` without a table lookup,
+    which on the TPU is a gather per code (seconds per full-model decode)."""
+    return pofx_normalized(codes.astype(jnp.int32), spec.N, spec.ES, spec.M,
+                           spec.rounding)[0]
 
 
 def _codes_to_values(codes, spec: QuantSpec) -> jax.Array:
@@ -139,22 +160,20 @@ def _codes_to_values(codes, spec: QuantSpec) -> jax.Array:
     The ONE copy of the hardware decode both the weight path (dequantize)
     and the KV-cache path (kv_dequantize, and tile-wise the flash-decode
     kernel) must agree on bit-for-bit: fxp is a two's-complement shift;
-    pofx goes stored posit -> bit-level LUT -> FxP(M, M-1) -> value.
+    pofx goes stored posit -> FxP(M, M-1) (``_pofx_fxp_codes``) -> value.
     """
     if spec.kind == "fxp":
         return _fxp.fxp_dequantize(codes, spec.F)
     if spec.kind == "pofx":
-        lut = jnp.asarray(pofx_norm_lut(spec.N, spec.ES, spec.M, spec.rounding))
-        fxp_codes = jnp.take(lut, codes.astype(jnp.int32), axis=0)
-        return _fxp.fxp_dequantize(fxp_codes, spec.M - 1)
+        return _fxp.fxp_dequantize(_pofx_fxp_codes(codes, spec), spec.M - 1)
     raise ValueError(f"no FxP decode path for kind {spec.kind!r}")
 
 
 def dequantize(qt: QuantizedTensor, dtype=jnp.bfloat16) -> jax.Array:
     """Recover float values as the *hardware* would see them.
 
-    pofx goes through the bit-level PoFx table: stored posit -> FxP(M, M-1)
-    two's-complement -> value * scale.  This is the datapath of Fig. 7.
+    pofx goes through the bit-level PoFx conversion: stored posit -> FxP(M,
+    M-1) two's-complement -> value * scale.  This is the datapath of Fig. 7.
     """
     spec = qt.spec
     if spec.kind in ("fp32", "bf16"):
@@ -172,8 +191,7 @@ def fxp_view(qt: QuantizedTensor):
     if spec.kind == "fxp":
         return qt.codes.astype(jnp.int8), qt.scale * (1.0 / (1 << spec.F))
     if spec.kind == "pofx":
-        lut = jnp.asarray(pofx_norm_lut(spec.N, spec.ES, spec.M, spec.rounding), jnp.int32)
-        codes = jnp.take(lut, qt.codes.astype(jnp.int32), axis=0).astype(jnp.int8)
+        codes = _pofx_fxp_codes(qt.codes, spec).astype(jnp.int8)
         return codes, qt.scale * (1.0 / (1 << (spec.M - 1)))
     raise ValueError(f"no FxP view for kind {spec.kind!r}")
 

@@ -25,7 +25,8 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, nk):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...].astype(jnp.int32), b_ref[...].astype(jnp.int32),
+    # int8 operands straight into the MXU: Mosaic refuses int32 dot operands
+    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
                             preferred_element_type=jnp.int32)
 
     @pl.when(k == nk - 1)

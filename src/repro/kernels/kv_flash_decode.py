@@ -31,6 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fxp import fxp_dequantize
 from repro.core.quantizers import QuantSpec
@@ -59,6 +60,7 @@ def _dequant_tile(codes, spec: QuantSpec, scale_row):
 def _kernel(pos_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
             m_ref, l_ref, acc_ref, *, spec: QuantSpec, bs: int, ns: int,
             scale: float):
+    b = pl.program_id(0)
     s = pl.program_id(2)
 
     @pl.when(s == 0)
@@ -71,7 +73,7 @@ def _kernel(pos_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
     k = _dequant_tile(kc_ref[0, 0], spec, ks_ref[0, 0])     # (bs, Dh)
     sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (R,bs)
     idx = s * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    sc = jnp.where(idx < pos_ref[0, 0], sc, NEG_INF)
+    sc = jnp.where(idx < pos_ref[b], sc, NEG_INF)
 
     m_prev, l_prev = m_ref[...], l_ref[...]                 # (R, 1)
     m_new = jnp.maximum(m_prev, sc.max(axis=-1, keepdims=True))
@@ -135,24 +137,30 @@ def kv_flash_decode(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
         k_codes = jnp.pad(k_codes, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v_codes = jnp.pad(v_codes, ((0, 0), (0, 0), (0, pad), (0, 0)))
     ns = (S + pad) // bs
-    pos2 = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)), (B, 1)).astype(jnp.int32)
+    pos1 = jnp.broadcast_to(jnp.reshape(pos, (-1,)), (B,)).astype(jnp.int32)
+    # pos rides in SMEM as a scalar-prefetch operand: a (1, 1) VMEM block of
+    # a (B, 1) array breaks the TPU's (8, 128) block-tiling rule.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, G, ns),
+        in_specs=[
+            pl.BlockSpec((1, 1, R, Dh), lambda b, g, s, pos: (b, g, 0, 0)),
+            pl.BlockSpec((1, 1, bs, Dh), lambda b, g, s, pos: (b, g, s, 0)),
+            pl.BlockSpec((1, 1, 1, Dh), lambda b, g, s, pos: (b, g, 0, 0)),
+            pl.BlockSpec((1, 1, bs, Dh), lambda b, g, s, pos: (b, g, s, 0)),
+            pl.BlockSpec((1, 1, 1, Dh), lambda b, g, s, pos: (b, g, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, R, Dh),
+                               lambda b, g, s, pos: (b, g, 0, 0)),
+        scratch_shapes=[vmem_scratch((R, 1)), vmem_scratch((R, 1)),
+                        vmem_scratch((R, Dh))],
+    )
     out = pl.pallas_call(
         functools.partial(_kernel, spec=spec, bs=bs, ns=ns,
                           scale=Dh ** -0.5),
-        grid=(B, G, ns),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, g, s: (b, 0)),            # pos
-            pl.BlockSpec((1, 1, R, Dh), lambda b, g, s: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, bs, Dh), lambda b, g, s: (b, g, s, 0)),
-            pl.BlockSpec((1, 1, 1, Dh), lambda b, g, s: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, bs, Dh), lambda b, g, s: (b, g, s, 0)),
-            pl.BlockSpec((1, 1, 1, Dh), lambda b, g, s: (b, g, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, R, Dh), lambda b, g, s: (b, g, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, R, Dh), out_dtype),
-        scratch_shapes=[vmem_scratch((R, 1)), vmem_scratch((R, 1)),
-                        vmem_scratch((R, Dh))],
         interpret=interpret,
-    )(pos2, q.astype(jnp.float32), k_codes, k_scale.astype(jnp.float32),
+    )(pos1, q.astype(jnp.float32), k_codes, k_scale.astype(jnp.float32),
       v_codes, v_scale.astype(jnp.float32))
     return out

@@ -8,9 +8,9 @@ QuantizedTensor weight it picks the right datapath —
   fxp    + int8 acts    -> int8 MXU MAC (fxp_matmul)
   others                -> dequantize + XLA matmul
 
-On this CPU container kernels run in interpret mode; on TPU they compile to
-Mosaic. ``use_kernel="auto"`` keeps kernels out of huge jit graphs (the
-dry-run lowers the XLA path; kernels are validated separately).
+``use_kernel`` is a plain bool set by the model (``build_model(...,
+use_kernel=True)``). Kernels compile to Mosaic on TPU and run in interpret
+mode on CPU.
 """
 from __future__ import annotations
 

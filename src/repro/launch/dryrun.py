@@ -1,5 +1,8 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+if __name__ == "__main__":
+    # 512 fake host devices for the production meshes: only when run as a
+    # program, never when imported.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract the roofline inputs from the compiled

@@ -11,17 +11,23 @@ Serving:    a 1-D ("tp",) mesh for the tensor-parallel engine
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_tp_mesh"]
+
+
+def _make_mesh(shape, axes, devices=None):
+    # jax.make_mesh defaults to Explicit axes; the model code shards through
+    # with_sharding_constraint and shard_map, which need Auto axes.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1,
@@ -29,7 +35,7 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 1,
     """Small mesh over whatever devices exist (tests / examples)."""
     shape = (pod, data, model) if pod > 1 else (data, model)
     axes = ("pod", "data", "model") if pod > 1 else ("data", "model")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return _make_mesh(shape, axes, devices)
 
 
 def make_tp_mesh(tp: int, devices=None):
@@ -48,4 +54,4 @@ def make_tp_mesh(tp: int, devices=None):
                 f"tp={tp} needs {tp} devices but only {len(avail)} exist "
                 "(CPU: set XLA_FLAGS=--xla_force_host_platform_device_count)")
         devices = avail[:tp]
-    return jax.make_mesh((tp,), ("tp",), devices=devices)
+    return _make_mesh((tp,), ("tp",), devices)

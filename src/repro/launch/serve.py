@@ -51,13 +51,38 @@ import numpy as np
 from repro.configs import ARCHS, RunConfig, smoke as smoke_cfg
 from repro.core.policy import (QuantPolicy, add_kv_quant_arg, add_policy_arg,
                                format_spec, resolve_kv_spec, storage_report)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.engine import Request, SamplingParams, ServeEngine
 from repro.launch.mesh import make_tp_mesh
-from repro.nn.models import (apply_policy, build_model,
-                             kv_decode_bytes_per_token)
+from repro.nn.models import build_model, kv_decode_bytes_per_token
 
 # Back-compat name; the policy-aware report lives in repro.core.policy.
 param_storage_report = storage_report
+
+
+def build_served_model(cfg, policy, kv_spec, *, use_kernel: bool,
+                       tp: int = 1, seed: int = 0):
+    """(model, params): the served model and its quantized parameters.
+
+    Parameters come from ``seed`` and are made and quantized one layer at a
+    time on the device (``LM.init_quantized``), so a model whose float
+    weights would not fit (yi-9b: 17.7 GB in bf16) serves at its published
+    width from its codes (~8.8 GB as pofx8). With ``tp > 1`` they come out
+    sharded over the ("tp",) mesh.
+    """
+    mesh = make_tp_mesh(tp) if tp > 1 else None
+    model = build_model(cfg, RunConfig(remat="none"), mesh=mesh,
+                        use_kernel=use_kernel, kv_spec=kv_spec)
+    return model, model.init_quantized(jax.random.PRNGKey(seed), policy)
+
+
+def memory_report(device=None) -> str:
+    """One line of ``device.memory_stats()``: bytes in use and the peak."""
+    stats = (device or jax.devices()[0]).memory_stats()
+    if not stats:
+        return "device memory: not reported by this backend"
+    return (f"device memory: {stats['bytes_in_use'] / 1e9:.2f} GB in use, "
+            f"peak {stats['peak_bytes_in_use'] / 1e9:.2f} GB")
 
 
 def _legacy_main(args, cfg, model, params) -> None:
@@ -165,11 +190,11 @@ def main(argv=None) -> None:
     ap.add_argument("--legacy", action="store_true",
                     help="one-shot fixed-batch greedy loop (no engine)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.smoke:
         cfg = smoke_cfg(cfg)
-    rcfg = RunConfig(remat="none")
     policy = QuantPolicy.from_string(args.quant)
     kv_spec = resolve_kv_spec(args.kv_quant, policy)
     if kv_spec is not None and cfg.family == "encdec":
@@ -180,16 +205,14 @@ def main(argv=None) -> None:
         ap.error("--tp needs the engine path (not --legacy / encdec)")
     if args.paged and (args.legacy or cfg.family == "encdec"):
         ap.error("--paged needs the engine path (not --legacy / encdec)")
-    mesh = make_tp_mesh(args.tp) if args.tp > 1 else None
-    model = build_model(cfg, rcfg, mesh=mesh, use_kernel=args.use_kernel,
-                        kv_spec=kv_spec)
-    params = model.init(jax.random.PRNGKey(0))
-    params = apply_policy(params, policy)
+    model, params = build_served_model(cfg, policy, kv_spec,
+                                       use_kernel=args.use_kernel, tp=args.tp)
     print(f"[{args.arch} quant={policy.to_string()} "
           f"kv={format_spec(kv_spec) if kv_spec else 'bf16'} "
           f"kernel={'pallas' if args.use_kernel else 'xla-lut'}"
           f"{f' tp={args.tp}' if args.tp > 1 else ''}]")
     print(storage_report(params, policy))
+    print(memory_report())
     ctx_len = args.prompt_len + args.gen
     kv_q = kv_decode_bytes_per_token(cfg, ctx_len, kv_spec)
     kv_b = kv_decode_bytes_per_token(cfg, ctx_len, None)

@@ -110,8 +110,12 @@ def dense_init(key, in_dim: int, out_dims, scale: Optional[float] = None,
     out_dims = (out_dims,) if isinstance(out_dims, int) else tuple(out_dims)
     if scale is None:
         scale = in_dim ** -0.5
-    return (jax.random.normal(key, (in_dim, *out_dims), dtype=jnp.float32)
-            * scale).astype(dtype)
+    # The barrier keeps XLA from folding ``scale`` into the normal's own
+    # constants inside a larger jit: a jitted init then draws the same bits
+    # as an eager one, which LM.init_quantized relies on.
+    w = jax.lax.optimization_barrier(
+        jax.random.normal(key, (in_dim, *out_dims), dtype=jnp.float32))
+    return (w * scale).astype(dtype)
 
 
 def mlp_init(key, d_model: int, d_ff: int, act: str, dtype=jnp.float32) -> dict:

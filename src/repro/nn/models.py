@@ -3,6 +3,7 @@
 Provides, for every assigned family (dense / moe / encdec / ssm / hybrid):
 
   init(key)            parameters (layer-stacked pytree, fp32 or bf16)
+  init_quantized()     init + apply_policy, one layer at a time (serving)
   logical()            logical-axis tree (same treedef) for sharding
   abstract_params()    ShapeDtypeStruct tree via eval_shape (dry-run: no alloc)
   forward()            full-sequence logits (train / prefill)
@@ -28,6 +29,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.configs.base import ModelConfig, RunConfig, ShapeConfig
 from repro.core.policy import QuantPolicy
@@ -124,6 +126,46 @@ class LM:
     # -- init / logical -------------------------------------------------------
 
     def init(self, key) -> Dict[str, Any]:
+        """Random parameters in ``weight_dtype``, layer-stacked."""
+        return self._init(key, lambda path, *args: T.stack_init(*args))
+
+    def init_quantized(self, key, policy) -> Dict[str, Any]:
+        """``apply_policy(self.init(key), policy)`` bit for bit, as one jitted
+        program that never holds the float model.
+
+        Each layer-stacked leaf is made one layer at a time (``lax.map`` over
+        the per-layer keys ``stack_init`` would vmap over) and quantized
+        before the next layer is made, so only codes, scales and
+        never-quantized leaves are ever full size. On a serving TP mesh the
+        parameters come out sharded as the engine places them, so no device
+        ever holds the whole model.
+        """
+        if isinstance(policy, str):
+            policy = QuantPolicy.from_string(policy)
+
+        def stack(path, block_init, k, n, *args):
+            return jax.lax.map(
+                lambda kk: _apply_policy(block_init(kk, *args), policy,
+                                         prefix=path, mapped=1),
+                jax.random.split(k, n))
+
+        def init(key):
+            params = self._init(key, stack)
+            stacked = ("blocks", "enc_blocks")
+            params.update(_apply_policy(
+                {k: v for k, v in params.items() if k not in stacked}, policy))
+            return params
+
+        shardings = None
+        if self.tp_size > 1:
+            specs = self.param_tp_specs(jax.eval_shape(init, key))
+            shardings = jax.tree.map(
+                lambda s: NamedSharding(self.ctx.mesh, s), specs)
+        return jax.jit(init, out_shardings=shardings)(key)
+
+    def _init(self, key, stack) -> Dict[str, Any]:
+        """The parameter tree; ``stack(path, block_init, key, n, *args)``
+        makes the ``n``-layer stack at ``path``."""
         cfg, dt = self.cfg, self.param_dtype
         ks = jax.random.split(key, 8)
         V, d = cfg.padded_vocab, cfg.d_model
@@ -135,26 +177,32 @@ class LM:
             params["unembed"] = dense_init(ks[1], d, V, dtype=dt)
         fam = cfg.family
         if fam == "dense":
-            params["blocks"] = T.stack_init(T.dense_block_init, ks[2], cfg.n_layers, cfg, dt)
+            params["blocks"] = stack(("blocks",), T.dense_block_init, ks[2],
+                                     cfg.n_layers, cfg, dt)
         elif fam == "moe":
             ng = self.n_groups
-            params["blocks"] = {"moe": T.stack_init(T.moe_block_init, ks[2], ng, cfg, dt)}
+            params["blocks"] = {"moe": stack(("blocks", "moe"),
+                                             T.moe_block_init, ks[2], ng,
+                                             cfg, dt)}
             if cfg.moe_every > 1:
                 def group_dense(k, cfg=cfg, dt=dt):
                     kk = jax.random.split(k, cfg.moe_every - 1)
                     return jax.vmap(lambda q: T.dense_block_init(q, cfg, dt))(kk)
-                params["blocks"]["dense"] = T.stack_init(group_dense, ks[3], ng)
+                params["blocks"]["dense"] = stack(("blocks", "dense"),
+                                                  group_dense, ks[3], ng)
         elif fam == "encdec":
-            params["enc_blocks"] = T.stack_init(
-                T.encdec_block_init, ks[2], cfg.n_enc_layers, cfg, dt)
+            params["enc_blocks"] = stack(("enc_blocks",), T.encdec_block_init,
+                                         ks[2], cfg.n_enc_layers, cfg, dt)
             params["enc_ln"] = jnp.ones((d,), dt)
-            params["blocks"] = T.stack_init(
-                functools.partial(T.encdec_block_init, cross=True),
+            params["blocks"] = stack(
+                ("blocks",), functools.partial(T.encdec_block_init, cross=True),
                 ks[3], cfg.n_layers, cfg, dt)
         elif fam == "ssm":
-            params["blocks"] = T.stack_init(T.mamba_block_init, ks[2], cfg.n_layers, cfg, dt)
+            params["blocks"] = stack(("blocks",), T.mamba_block_init, ks[2],
+                                     cfg.n_layers, cfg, dt)
         elif fam == "hybrid":
-            params["blocks"] = T.stack_init(T.mamba_block_init, ks[2], cfg.n_layers, cfg, dt)
+            params["blocks"] = stack(("blocks",), T.mamba_block_init, ks[2],
+                                     cfg.n_layers, cfg, dt)
             params["shared"] = T.dense_block_init(ks[3], cfg, dt)
         else:
             raise ValueError(f"unknown family {fam!r}")
@@ -1008,17 +1056,26 @@ def apply_policy(params, policy):
     """
     if isinstance(policy, str):
         policy = QuantPolicy.from_string(policy)
+    return _apply_policy(params, policy)
+
+
+def _apply_policy(params, policy: QuantPolicy, prefix=(), mapped: int = 0):
+    """``apply_policy`` on a subtree found at path ``prefix`` whose first
+    ``mapped`` layer-stacking axes are mapped away (``LM.init_quantized``
+    quantizes one layer of the stack at a time)."""
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     treedef = jax.tree_util.tree_structure(params)
     out = []
     for path, leaf in flat:
-        names = [str(getattr(k, "key", getattr(k, "name", k))) for k in path]
+        names = list(prefix) + [str(getattr(k, "key", getattr(k, "name", k)))
+                                for k in path]
         name = "/".join(names)
         # layer-stacked leaves must keep per-layer scales (leading dims stay
         # mapped) so lax.scan can slice codes and scale together.
         stack_depth = 0
         if "blocks" in names or "enc_blocks" in names:
             stack_depth = 2 if "dense" in names else 1
+        stack_depth -= mapped
         eligible = (leaf.ndim >= 2 + stack_depth
                     and not any(t in name for t in _NEVER_QUANT))
         spec = policy.match(name) if eligible else None
@@ -1029,7 +1086,7 @@ def apply_policy(params, policy):
             dt = jnp.float32 if spec.kind == "fp32" else jnp.bfloat16
             out.append(jnp.asarray(leaf).astype(dt))
             continue
-        fn = lambda w: quantize(w.astype(jnp.float32), spec, axis=-1)
+        fn = lambda w: quantize(w, spec, axis=-1)
         for _ in range(stack_depth):
             fn = jax.vmap(fn)
         out.append(fn(jnp.asarray(leaf)))

@@ -30,7 +30,7 @@ def moe_init(key, cfg, dtype=jnp.float32) -> dict:
     gated = is_gated(cfg.act)
     p = {"router": dense_init(ks[0], d, E, dtype=jnp.float32)}
     def ew(k, i, o):  # stacked expert weights (E, in, out)
-        return (jax.random.normal(k, (E, i, o)) * i ** -0.5).astype(dtype)
+        return dense_init(k, E, (i, o), scale=i ** -0.5, dtype=dtype)
     if gated:
         p.update(wg=ew(ks[1], d, ff), wu=ew(ks[2], d, ff), wo=ew(ks[3], ff, d))
     else:

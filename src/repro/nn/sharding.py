@@ -197,23 +197,16 @@ def manual_tp_ctx(axis: str = TP_AXIS) -> ShardingCtx:
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, manual_axes=None):
-    """jax.shard_map across jax versions — the ONE shim both users share
-    (the TP serving engine and the posit8-compressed train step): new API
-    (axis_names/check_vma) when available, else jax.experimental.shard_map
-    (auto/check_rep=False — pallas calls inside carry no replication rule).
+    """``jax.shard_map`` as both users call it (the TP serving engine and
+    the posit8-compressed train step): ``check_vma=False`` because pallas
+    calls inside carry no replication rule.
 
     ``manual_axes`` defaults to every mesh axis (the serving-TP case);
     pass a subset for partial-manual (train's pod-only grad transport).
     """
     manual = set(manual_axes if manual_axes is not None else mesh.axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(a for a in mesh.axis_names if a not in manual)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               auto=auto, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         axis_names=manual, check_vma=False)
 
 
 def logical_specs(ctx: ShardingCtx, logical: Any, abstract: Any) -> Any:

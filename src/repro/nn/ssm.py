@@ -29,6 +29,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .layers import Param, dense_init, matmul_param, param_value, rmsnorm
 
@@ -41,17 +42,19 @@ def mamba1_init(key, cfg, dtype=jnp.float32) -> dict:
     d, di, ds, dr = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.dt_rank
     ks = jax.random.split(key, 6)
     # A initialized to -[1..ds] per channel (S4D-real), stored as log.
-    a = jnp.tile(jnp.arange(1, ds + 1, dtype=jnp.float32)[None, :], (di, 1))
+    # (host constants: XLA would constant-fold a jnp.log inside a jit with
+    # other rounding than it computes it eagerly)
+    a = np.tile(np.arange(1, ds + 1, dtype=np.float64)[None, :], (di, 1))
     return {
         "in_proj": dense_init(ks[0], d, 2 * di, dtype=dtype),
-        "conv_w": (jax.random.normal(ks[1], (cfg.conv_width, di)) * 0.1).astype(dtype),
+        "conv_w": dense_init(ks[1], cfg.conv_width, di, scale=0.1, dtype=dtype),
         "conv_b": jnp.zeros((di,), dtype),
         "x_proj": dense_init(ks[2], di, dr + 2 * ds, dtype=dtype),
         "dt_proj": dense_init(ks[3], dr, di, scale=dr**-0.5, dtype=dtype),
         "dt_bias": jnp.log(jnp.expm1(jnp.exp(
             jax.random.uniform(ks[4], (di,), minval=jnp.log(1e-3), maxval=jnp.log(1e-1))
         ))).astype(jnp.float32),
-        "A_log": jnp.log(a),                       # fp32 always
+        "A_log": jnp.asarray(np.log(a), jnp.float32),   # fp32 always
         "D": jnp.ones((di,), jnp.float32),         # fp32 always
         "out_proj": dense_init(ks[5], di, d, dtype=dtype),
     }
@@ -190,9 +193,10 @@ def mamba2_init(key, cfg, dtype=jnp.float32) -> dict:
     # in_proj packs [z (di), x (di), B (ds), C (ds), dt (nh)]
     return {
         "in_proj": dense_init(ks[0], d, 2 * di + 2 * ds + nh, dtype=dtype),
-        "conv_w": (jax.random.normal(ks[1], (cfg.conv_width, di + 2 * ds)) * 0.1).astype(dtype),
+        "conv_w": dense_init(ks[1], cfg.conv_width, di + 2 * ds, scale=0.1,
+                             dtype=dtype),
         "conv_b": jnp.zeros((di + 2 * ds,), dtype),
-        "A_log": jnp.log(jnp.linspace(1.0, 16.0, nh)).astype(jnp.float32),
+        "A_log": jnp.asarray(np.log(np.linspace(1.0, 16.0, nh)), jnp.float32),
         "dt_bias": jnp.zeros((nh,), jnp.float32),
         "D": jnp.ones((nh,), jnp.float32),
         "norm_w": jnp.ones((di,), dtype),

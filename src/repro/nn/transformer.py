@@ -27,6 +27,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.quantizers import QuantizedTensor, dequantize
+
 from . import ssm
 from .attention import attn_forward, attn_init, attn_logical
 from .layers import dense_init, matmul_param, mlp_forward, mlp_init, mlp_logical, rmsnorm
@@ -210,10 +212,17 @@ def sinusoid_table(max_len: int, d: int) -> jnp.ndarray:
 
 
 def embed_tokens(emb, tokens, ctx, dtype=jnp.bfloat16):
-    """Vocab-parallel embedding lookup (one-hot matmul keeps GSPMD happy)."""
+    """Embedding lookup. A quantized table is indexed before it is decoded:
+    decoding all of it (yi-9b: 262M codes) on every step cost a gather over
+    every code, which dominated a decode step on the TPU."""
     from .layers import param_value
-    table = param_value(emb, dtype)
-    x = jnp.take(table, tokens, axis=0)
+    if isinstance(emb, QuantizedTensor):
+        # scales are per output channel (1, d_model): they broadcast over rows
+        rows = QuantizedTensor(jnp.take(emb.codes, tokens, axis=0), emb.scale,
+                               emb.spec)
+        x = dequantize(rows, dtype)
+    else:
+        x = jnp.take(param_value(emb, dtype), tokens, axis=0)
     return ctx.constrain(x, "batch", "seq", None)
 
 

@@ -66,10 +66,15 @@ def test_pofx_normalized_exhaustive(N, ES):
     assert np.all(np.abs(out) <= (1 << (M - 1)) - 1)
 
 
-def test_pofx_normalized_jnp_matches_np():
-    nm = np.arange(1 << 7)
-    o1, f1 = pofx_normalized_np(nm, 8, 2, 8)
-    o2, f2 = pofx_normalized(jnp.asarray(nm), 8, 2, 8)
+@pytest.mark.parametrize("N,ES,M,rounding", [
+    (8, 2, 8, "trunc"), (8, 2, 8, "nearest"), (6, 1, 8, "trunc"),
+    (9, 3, 12, "trunc"), (10, 0, 16, "nearest")])
+def test_pofx_normalized_jnp_matches_np(N, ES, M, rounding):
+    """The int32 jnp conversion (the weight decode's and the kernels')
+    equals the int64 numpy one, and so the decode table, on every code."""
+    nm = np.arange(1 << (N - 1))
+    o1, f1 = pofx_normalized_np(nm, N, ES, M, rounding)
+    o2, f2 = pofx_normalized(jnp.asarray(nm), N, ES, M, rounding)
     assert np.array_equal(o1, np.asarray(o2))
     assert np.array_equal(f1, np.asarray(f2))
 

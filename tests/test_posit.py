@@ -93,6 +93,20 @@ def test_encode_jnp_matches_np(N, ES):
     assert np.array_equal(a, b)
 
 
+@pytest.mark.parametrize("N,ES", [(8, 2), (6, 1), (9, 3)])
+def test_encode_selects_match_gathers(N, ES, monkeypatch):
+    """Small tables are searched and read with compares and selects (no
+    TPU gathers); the codes are those of the gather-based search."""
+    from repro.core import posit
+    rng = np.random.default_rng(2)
+    x = (rng.standard_normal(4096) * np.exp2(rng.integers(-20, 10, 4096))).astype(np.float32)
+    x[:3] = [0.0, np.nan, -np.inf]
+    selects = np.asarray(posit_encode(jnp.asarray(x), N, ES))
+    monkeypatch.setattr(posit, "_SELECT_MAX", 0)
+    gathers = np.asarray(posit_encode(jnp.asarray(x), N, ES))
+    assert np.array_equal(selects, gathers)
+
+
 @given(seed=7, examples=30, x=Floats(lo=-1e6, hi=1e6, shape=(256,)))
 def test_encode_is_nearest(x):
     """Property: |decode(encode(x)) - x| <= distance to any lattice value."""

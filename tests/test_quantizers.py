@@ -42,6 +42,28 @@ def test_quantize_dequantize_bounded_error(spec):
     assert err < 5e-3, (spec, err)
 
 
+@pytest.mark.parametrize("spec", [
+    QuantSpec(kind="fxp", M=8, F=7),
+    QuantSpec(kind="posit", N=8, ES=2),
+    QuantSpec(kind="pofx", N=8, ES=2, path="via_fxp"),
+    QuantSpec(kind="pofx", N=8, ES=2, path="direct"),
+])
+def test_large_leaf_encodes_by_row_blocks_identically(spec, monkeypatch):
+    """A leaf above the block size encodes a block of rows at a time (the
+    last block short) and gives the codes a whole-array encode gives."""
+    from repro.core import quantizers
+    w = _weights((1100, 4096)).astype(jnp.bfloat16)
+    assert w.size > quantizers._ENCODE_BLOCK > 4096 * 1000
+    blocked = quantize(w, spec, axis=-1)
+    monkeypatch.setattr(quantizers, "_ENCODE_BLOCK", w.size)
+    whole = quantize(w, spec, axis=-1)
+    assert blocked.codes.dtype == whole.codes.dtype
+    np.testing.assert_array_equal(np.asarray(blocked.codes),
+                                  np.asarray(whole.codes))
+    np.testing.assert_array_equal(np.asarray(blocked.scale),
+                                  np.asarray(whole.scale))
+
+
 def test_quantized_tensor_is_pytree():
     w = _weights((16, 8))
     qt = quantize(w, QuantSpec(kind="pofx", N=8, ES=2), axis=-1)

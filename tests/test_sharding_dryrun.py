@@ -39,13 +39,6 @@ def test_sharding_rules_divisibility_fallback():
 def test_dryrun_subprocess_small_mesh(tmp_path):
     """End-to-end: lower+compile a smoke arch on 8 fake devices, parse HLO,
     roofline terms present. Mirrors launch/dryrun.py in miniature."""
-    import jax
-    if not hasattr(jax, "shard_map"):
-        # posit8 grad compression runs shard_map manual over "pod" with
-        # data/model left auto; old-API jax (experimental.shard_map + this
-        # container's XLA) CHECK-fails on that partial-manual partition
-        # (hlo_sharding_util IsManualSubgroup). Needs jax>=0.6.
-        pytest.skip("partial-manual shard_map unsupported by this jax/XLA")
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -58,8 +51,9 @@ from repro.launch.train import (make_train_step, abstract_train_state,
                                 state_shardings, batch_shardings)
 from repro.launch.hlo_parser import analyze_hlo
 from repro.launch.hlo_analysis import roofline_terms
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_host_mesh(pod=2, data=2, model=2)
 cfg = smoke(ARCHS["yi-9b"])
 rcfg = RunConfig(remat="block", sequence_parallel=True, microbatch=2,
                  grad_compression="posit8")
@@ -114,8 +108,9 @@ from repro.configs import ARCHS, RunConfig, smoke
 from repro.core.quantizers import QuantSpec, QuantizedTensor
 from repro.nn.models import build_model, quantize_params
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(data=4, model=2)
 cfg = smoke(ARCHS["deepseek-67b"])
 model = build_model(cfg, RunConfig(remat="none"), mesh=mesh)
 spec = QuantSpec(kind="pofx", N=8, ES=2, M=8)
