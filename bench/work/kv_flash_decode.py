@@ -1,0 +1,26 @@
+"""``kernels/kv_flash_decode.py``: one query token per slot against a
+heads-major cache of byte-wide K/V codes with per-channel float32 scales.
+Only each slot's live context counts: the positions it attends to."""
+from __future__ import annotations
+
+from typing import Sequence
+
+
+def work(heads: int, kv_heads: int, head_dim: int,
+         lengths: Sequence[int]) -> tuple:
+    """(FLOPs, HBM bytes) of one layer's call over slots that attend to
+    ``lengths`` positions each."""
+    ctx = float(sum(lengths))
+    n = len(lengths)
+    flops = 4.0 * heads * head_dim * ctx              # q.k and p.v
+    nbytes = (2.0 * kv_heads * head_dim * ctx           # K and V codes
+              + n * (2 * kv_heads * head_dim * 4        # scales
+                     + 2 * heads * head_dim * 4))       # q in, out
+    return flops, nbytes
+
+
+def decode_step_work(config: dict, lengths: Sequence[int]) -> tuple:
+    from bench.work.model import dims
+    m = dims(config)
+    f, b = work(m["H"], m["G"], m["Dh"], lengths)
+    return m["L"] * f, m["L"] * b
