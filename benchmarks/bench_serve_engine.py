@@ -292,26 +292,24 @@ def run(use_kernel: bool = False, quant: str = "pofx8",
                             prompt=rng.integers(0, cfg.vocab_size, sz.prompt),
                             max_new=sz.gen, sampling=SamplingParams())
                     for i in range(sz.slots)])
-        engine.prefill_time = engine.decode_time = 0.0
-        engine.decode_steps = 0
         engine.clock = 0.0  # warmup must not shift the measured arrivals
-        warm_gen = engine.stats()["generated_tokens"]
-        warm_sampled = engine.n_prefill_sampled
+        warm = engine.stats()
         engine.run(reqs)
         st = engine.stats()
-        n_gen = st["generated_tokens"] - warm_gen
-        n_dec = n_gen - (engine.n_prefill_sampled - warm_sampled)
+        n_gen = st["generated_tokens"] - warm["generated_tokens"]
+        n_dec = st["decode_tokens"] - warm["decode_tokens"]
+        decode_s = st["decode_time_s"] - warm["decode_time_s"]
         rows.append({
             "mix": mix, "arch": ARCH, "quant": quant,
             "use_kernel": use_kernel, "slots": sz.slots,
             "requests": sz.n_req,
             "prompt_len": sz.prompt, "gen": sz.gen,
             "generated_tokens": n_gen,
-            "decode_steps": st["decode_steps"],
-            "decode_tok_per_s": round(n_dec / max(st["decode_time_s"], 1e-9),
-                                      2),
-            "prefill_s": round(st["prefill_time_s"], 4),
-            "decode_s": round(st["decode_time_s"], 4),
+            "decode_steps": st["decode_steps"] - warm["decode_steps"],
+            "decode_tok_per_s": round(n_dec / max(decode_s, 1e-9), 2),
+            "prefill_s": round(st["prefill_time_s"] - warm["prefill_time_s"],
+                               4),
+            "decode_s": round(decode_s, 4),
         })
     by_mix = {r["mix"]: r["decode_tok_per_s"] for r in rows}
     claims = {

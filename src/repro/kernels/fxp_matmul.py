@@ -60,5 +60,6 @@ def fxp_matmul(a: jax.Array, b: jax.Array, blocks=None,
         out_shape=jax.ShapeDtypeStruct((ap.shape[0], bp.shape[1]), jnp.int32),
         scratch_shapes=[vmem_scratch((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="fxp_matmul",
     )(ap, bp)
     return out[:m, :n]

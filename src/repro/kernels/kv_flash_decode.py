@@ -161,6 +161,7 @@ def kv_flash_decode(q: jax.Array, k_codes: jax.Array, k_scale: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, R, Dh), out_dtype),
         interpret=interpret,
+        name="kv_flash_decode",
     )(pos1, q.astype(jnp.float32), k_codes, k_scale.astype(jnp.float32),
       v_codes, v_scale.astype(jnp.float32))
     return out

@@ -145,6 +145,7 @@ def kv_flash_paged_decode(q: jax.Array, k_pool: jax.Array, k_scale: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G, R, Dh), out_dtype),
         interpret=interpret,
+        name="kv_flash_paged_decode",
     )(tables.astype(jnp.int32), pos2, q.astype(jnp.float32), k_pool,
       k_scale.astype(jnp.float32), v_pool, v_scale.astype(jnp.float32))
     return out

@@ -49,5 +49,6 @@ def pofx_decode(codes: jax.Array, N: int, ES: int, M: int = 8,
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(padded.shape, jnp.int8),
         interpret=interpret,
+        name="pofx_decode",
     )(padded)
     return out[:r, :c]

@@ -100,5 +100,6 @@ def pofx_matmul(x: jax.Array, codes: jax.Array, scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], cp.shape[1]), out_dtype),
         scratch_shapes=[vmem_scratch((bm, bn))],
         interpret=interpret,
+        name="pofx_matmul",
     )(xp, cp, sp, lut)
     return out[:m, :n]

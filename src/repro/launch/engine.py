@@ -75,6 +75,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .tracing import SpanTotals
+
 __all__ = ["SamplingParams", "Request", "RequestState", "ServeEngine",
            "sample_tokens"]
 
@@ -107,6 +109,9 @@ class RequestState:
     admitted_at: float = -1.0
     finished_at: float = -1.0
     n_evictions: int = 0
+    submitted_s: float = -1.0    # host clock (perf_counter) at submit
+    admitted_s: float = -1.0     # host clock at the start of its first
+    #   admission
 
     @property
     def done(self) -> bool:
@@ -249,12 +254,21 @@ class ServeEngine:
         self._pending: Deque[int] = deque()
         self._done_box: List[RequestState] = []
         self.clock = 0.0              # virtual time = decode steps executed
-        self.prefill_time = 0.0
-        self.decode_time = 0.0
         self.decode_steps = 0
         self.n_prefill_sampled = 0    # tokens sampled from prefill logits
         #   (one per admission, so one per request plus one per eviction —
         #    the exact complement of decode-generated tokens)
+        # running counters reported by stats(), which must stay O(1): the
+        # benchmark's client reads it between engine calls
+        self.generated_tokens = 0
+        self.live_slot_steps = 0      # slot-steps that decoded a token
+        self.live_context_sum = 0     # their attended lengths, summed
+        self.admissions = 0
+        self.admitted_prompt_tokens = 0
+        self.prefill_tokens = 0       # run through prefill (shared prefix
+        #   excluded)
+        self.n_chunks = 0
+        self._spans = SpanTotals()
 
         self._chunk_fn = jax.jit(
             self._chunk_wrap,
@@ -270,9 +284,32 @@ class ServeEngine:
         else:
             self._scatter_fn = jax.jit(self._scatter_impl, donate_argnums=(0,))
             self._prefill_fn = jax.jit(self._prefill_wrap, donate_argnums=(1,))
-        self._sample_fn = jax.jit(sample_tokens)
+        self._sample_fn = jax.jit(self._sample_impl)
+        # the engine's jitted device programs, whose compiled variants
+        # stats() counts
+        self._programs = {"decode_chunk": self._chunk_fn,
+                          "sample": self._sample_fn}
+        if self.paged:
+            self._programs.update(prefill_paged=self._prefill_paged_fn,
+                                  copy_page=self._copy_page_fn)
+        else:
+            self._programs.update(prefill=self._prefill_fn,
+                                  scatter=self._scatter_fn)
 
     # -- scheduler (host) ----------------------------------------------------
+
+    @property
+    def decode_time(self) -> float:
+        """Host seconds from launching each decode chunk to holding its
+        tokens (``serve.step.launch`` + ``serve.step.read``)."""
+        return (self._spans.seconds("serve.step.launch")
+                + self._spans.seconds("serve.step.read"))
+
+    @property
+    def prefill_time(self) -> float:
+        """Host seconds admitting requests (``serve.admit``): plan, prefill
+        and the wait for the first token."""
+        return self._spans.seconds("serve.admit")
 
     @property
     def free_slots(self) -> List[int]:
@@ -298,8 +335,14 @@ class ServeEngine:
                 f"max_len {self.max_len}")
         if req.max_new < 1:
             raise ValueError(f"request {req.rid}: max_new must be >= 1")
-        self._states[req.rid] = RequestState(req=req, context=prompt)
+        self._states[req.rid] = RequestState(
+            req=req, context=prompt, submitted_s=time.perf_counter())
         self._pending.append(req.rid)
+
+    def request(self, rid: int) -> RequestState:
+        """The state of request ``rid``: its tokens so far, slot, finish
+        reason and host-clock submit / first-admission times."""
+        return self._states[rid]
 
     def evict(self, rid: int) -> None:
         """Preempt a running request back to the head of the pending queue.
@@ -367,34 +410,39 @@ class ServeEngine:
         P = int(ctx.size)
         Pb = min(-(-P // self.prompt_bucket) * self.prompt_bucket,
                  self.max_len)
-        t0 = time.perf_counter()
-        # prompt_bucket > 1 means the operator asked for bounded prefill
-        # compile variants — page-align the prefix hit too, since each
-        # distinct prefix_len is a fresh compile (exact-length serving,
-        # bucket 1, keeps token-granular sharing and recompiles per
-        # length, exactly like dense prefill does)
-        plan = self._pager.admit(rid, ctx.tolist(), Pb,
-                                 page_align=self.prompt_bucket > 1)
-        prefix_len = int(plan.prefix_len)
-        for src, dst in plan.copies:
-            self.cache = self._copy_page_fn(self.cache,
-                                            jnp.asarray(src, jnp.int32),
-                                            jnp.asarray(dst, jnp.int32))
-        self.cache["pages"] = self.cache["pages"].at[slot].set(
-            jnp.asarray(plan.table))
-        n_suffix = P - prefix_len
-        padded = np.full((1, Pb - prefix_len), self.pad_id, np.int32)
-        padded[0, :n_suffix] = ctx[prefix_len:]
-        self.cache, logits = self._prefill_paged_fn(
-            self.params, self.cache, jnp.asarray(padded),
-            jnp.asarray(slot, jnp.int32), jnp.asarray(n_suffix, jnp.int32),
-            prefix_len=prefix_len)
-        # index the prompt's pages so concurrent/later requests with the
-        # same system prompt skip its prefill (content is final: writes
-        # past P only ever touch offsets beyond the registered valid run)
-        self._pager.register(rid, ctx.tolist(), P)
-        self._slot_pos[slot] = P
-        self._finish_admit(rid, slot, P, logits, t0)
+        with self._admit_span(st, P) as admit:
+            with self._spans.span("serve.admit.plan"):
+                # prompt_bucket > 1 means the operator asked for bounded
+                # prefill compile variants — page-align the prefix hit too,
+                # since each distinct prefix_len is a fresh compile
+                # (exact-length serving, bucket 1, keeps token-granular
+                # sharing and recompiles per length, exactly like dense
+                # prefill does)
+                plan = self._pager.admit(rid, ctx.tolist(), Pb,
+                                         page_align=self.prompt_bucket > 1)
+                prefix_len = int(plan.prefix_len)
+                for src, dst in plan.copies:
+                    self.cache = self._copy_page_fn(
+                        self.cache, jnp.asarray(src, jnp.int32),
+                        jnp.asarray(dst, jnp.int32))
+                self.cache["pages"] = self.cache["pages"].at[slot].set(
+                    jnp.asarray(plan.table))
+            admit.annotation.set_metadata(prefix_tokens=prefix_len)
+            n_suffix = P - prefix_len
+            padded = np.full((1, Pb - prefix_len), self.pad_id, np.int32)
+            padded[0, :n_suffix] = ctx[prefix_len:]
+            with self._spans.span("serve.admit.prefill"):
+                self.cache, logits = self._prefill_paged_fn(
+                    self.params, self.cache, jnp.asarray(padded),
+                    jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(n_suffix, jnp.int32), prefix_len=prefix_len)
+            # index the prompt's pages so concurrent/later requests with the
+            # same system prompt skip its prefill (content is final: writes
+            # past P only ever touch offsets beyond the registered valid run)
+            self._pager.register(rid, ctx.tolist(), P)
+            self._slot_pos[slot] = P
+            tok0 = self._first_token(rid, slot, P, logits)
+        self._publish(rid, slot, tok0, n_suffix)
 
     def _admit(self, rid: int, slot: int) -> None:
         if self.paged:
@@ -408,43 +456,62 @@ class ServeEngine:
                  self.max_len)
         padded = np.full((1, Pb), self.pad_id, np.int32)
         padded[0, :P] = ctx
-        t0 = time.perf_counter()
-        small = self.model.init_cache(1, self.max_len)
-        small = self._seed_kv_scales(small, slot)
-        # bucket 1 means exact-length prompts: take the length=None path so
-        # SSM/hybrid (which refuse right-padded prefill) serve too.
-        length = None if Pb == P else jnp.asarray(P, jnp.int32)
-        small, logits = self._prefill_fn(
-            self.params, small, jnp.asarray(padded), length)
-        self.cache = self._scatter_fn(self.cache, small,
-                                      jnp.asarray(slot, jnp.int32))
-        self._finish_admit(rid, slot, P, logits, t0)
+        with self._admit_span(st, P, prefix_tokens=0):
+            with self._spans.span("serve.admit.plan"):
+                small = self.model.init_cache(1, self.max_len)
+                small = self._seed_kv_scales(small, slot)
+            # bucket 1 means exact-length prompts: take the length=None path
+            # so SSM/hybrid (which refuse right-padded prefill) serve too.
+            length = None if Pb == P else jnp.asarray(P, jnp.int32)
+            with self._spans.span("serve.admit.prefill"):
+                small, logits = self._prefill_fn(
+                    self.params, small, jnp.asarray(padded), length)
+            with self._spans.span("serve.admit.scatter"):
+                self.cache = self._scatter_fn(self.cache, small,
+                                              jnp.asarray(slot, jnp.int32))
+            tok0 = self._first_token(rid, slot, P, logits)
+        self._publish(rid, slot, tok0, P)
 
-    def _finish_admit(self, rid: int, slot: int, P: int, logits,
-                      t0: float) -> None:
-        """Shared admission tail: sample the first token from the prefill
-        logits (key folds the ABSOLUTE position P-1, so paged and dense
-        admissions draw the identical stream), publish slot state, retire
-        immediately on eos/length."""
+    def _admit_span(self, st: RequestState, P: int, **ids):
+        if st.admitted_s < 0:
+            st.admitted_s = time.perf_counter()
+        return self._spans.span("serve.admit", rid=st.req.rid,
+                                prompt_tokens=P, **ids)
+
+    def _first_token(self, rid: int, slot: int, P: int, logits) -> int:
+        """Sample the first token from the prefill logits (key folds the
+        ABSOLUTE position P-1, so paged and dense admissions draw the
+        identical stream), set the slot's token and key, and wait for
+        them: the admission's only waits on the device."""
         st = self._states[rid]
-        key = jax.random.fold_in(self._base_key, rid)
-        st0 = st.req.sampling
-        tok0 = self._sample_fn(
-            logits, jax.random.fold_in(key, P - 1)[None],
-            jnp.asarray([st0.temperature], jnp.float32),
-            jnp.asarray([st0.top_k], jnp.int32))
-        tok0 = int(tok0[0])
-        self._tok = self._tok.at[slot, 0].set(tok0)
-        self._keys = self._keys.at[slot].set(key)
-        jax.block_until_ready(self._tok)
-        self.prefill_time += time.perf_counter() - t0
+        with self._spans.span("serve.admit.read"):
+            key = jax.random.fold_in(self._base_key, rid)
+            st0 = st.req.sampling
+            tok0 = self._sample_fn(
+                logits, jax.random.fold_in(key, P - 1)[None],
+                jnp.asarray([st0.temperature], jnp.float32),
+                jnp.asarray([st0.top_k], jnp.int32))
+            tok0 = int(tok0[0])
+            self._tok = self._tok.at[slot, 0].set(tok0)
+            self._keys = self._keys.at[slot].set(key)
+            jax.block_until_ready(self._tok)
+        return tok0
 
+    def _publish(self, rid: int, slot: int, tok0: int, prefilled: int
+                 ) -> None:
+        """Shared admission tail: publish slot state, count the admission,
+        retire immediately on eos/length."""
+        st = self._states[rid]
         self._slot_rid[slot] = rid
         st.slot = slot
         if st.admitted_at < 0:
             st.admitted_at = self.clock
         st.out.append(tok0)
+        self.generated_tokens += 1
         self.n_prefill_sampled += 1
+        self.admissions += 1
+        self.admitted_prompt_tokens += int(st.context.size)
+        self.prefill_tokens += int(prefilled)
         if self.eos_id is not None and tok0 == self.eos_id:
             self._finish(rid, "eos")
         elif len(st.out) >= self._eff_max_new(st):
@@ -485,18 +552,19 @@ class ServeEngine:
         replicated. ``length`` may be None (exact-length prompts) — an
         empty pytree, which shard_map broadcasts a spec over harmlessly.
         """
-        if self.tp == 1:
-            return self.model.prefill(params, tokens, cache=cache,
-                                      length=length)
-        from repro.nn.sharding import shard_map_compat
-        mm = self._mm
-        fn = lambda p, c, t, l: mm.prefill(p, t, cache=c, length=l)
-        rep = P()
-        return shard_map_compat(
-            fn, self._mesh,
-            in_specs=(self._param_specs, self._small_specs, rep, rep),
-            out_specs=(self._small_specs, rep),
-        )(params, cache, tokens, length)
+        with jax.named_scope("serve_prefill"):
+            if self.tp == 1:
+                return self.model.prefill(params, tokens, cache=cache,
+                                          length=length)
+            from repro.nn.sharding import shard_map_compat
+            mm = self._mm
+            fn = lambda p, c, t, l: mm.prefill(p, t, cache=c, length=l)
+            rep = P()
+            return shard_map_compat(
+                fn, self._mesh,
+                in_specs=(self._param_specs, self._small_specs, rep, rep),
+                out_specs=(self._small_specs, rep),
+            )(params, cache, tokens, length)
 
     def _prefill_paged_wrap(self, params, cache, tokens, slot, length, *,
                             prefix_len: int):
@@ -505,20 +573,22 @@ class ServeEngine:
         / length / logits replicate. ``prefix_len`` is static (it fixes
         gather sizes and the attention bias offset), so each distinct
         shared-prefix length compiles once."""
-        if self.tp == 1:
-            return self.model.prefill_paged(params, tokens, cache=cache,
-                                            slot=slot, length=length,
-                                            prefix_len=prefix_len)
-        from repro.nn.sharding import shard_map_compat
-        mm = self._mm
-        fn = lambda p, c, t, s, l: mm.prefill_paged(
-            p, t, cache=c, slot=s, length=l, prefix_len=prefix_len)
-        rep = P()
-        return shard_map_compat(
-            fn, self._mesh,
-            in_specs=(self._param_specs, self._cache_specs, rep, rep, rep),
-            out_specs=(self._cache_specs, rep),
-        )(params, cache, tokens, slot, length)
+        with jax.named_scope("serve_prefill_paged"):
+            if self.tp == 1:
+                return self.model.prefill_paged(params, tokens, cache=cache,
+                                                slot=slot, length=length,
+                                                prefix_len=prefix_len)
+            from repro.nn.sharding import shard_map_compat
+            mm = self._mm
+            fn = lambda p, c, t, s, l: mm.prefill_paged(
+                p, t, cache=c, slot=s, length=l, prefix_len=prefix_len)
+            rep = P()
+            return shard_map_compat(
+                fn, self._mesh,
+                in_specs=(self._param_specs, self._cache_specs, rep, rep,
+                          rep),
+                out_specs=(self._cache_specs, rep),
+            )(params, cache, tokens, slot, length)
 
     def _copy_page_impl(self, cache, src, dst):
         """Copy page ``src`` -> ``dst`` in every pool code leaf (the
@@ -527,15 +597,16 @@ class ServeEngine:
         moe's extra layer-stacking dims need no special-casing."""
         flat, treedef = jax.tree_util.tree_flatten(cache)
         out = []
-        for leaf, ax in zip(flat, self._cache_log_flat):
-            if "kv_pages" not in ax:
-                out.append(leaf)
-                continue
-            axis = ax.index("kv_pages")
-            page = jax.lax.dynamic_index_in_dim(leaf, src, axis,
-                                                keepdims=False)
-            out.append(jax.lax.dynamic_update_index_in_dim(
-                leaf, page, dst, axis))
+        with jax.named_scope("serve_copy_page"):
+            for leaf, ax in zip(flat, self._cache_log_flat):
+                if "kv_pages" not in ax:
+                    out.append(leaf)
+                    continue
+                axis = ax.index("kv_pages")
+                page = jax.lax.dynamic_index_in_dim(leaf, src, axis,
+                                                    keepdims=False)
+                out.append(jax.lax.dynamic_update_index_in_dim(
+                    leaf, page, dst, axis))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     def _chunk_wrap(self, params, cache, tok, done, n_gen, keys, temps,
@@ -553,17 +624,18 @@ class ServeEngine:
         impl = functools.partial(self._chunk_impl, steps=steps, eos=eos,
                                  pad=pad, greedy_only=greedy_only,
                                  topk_any=topk_any)
-        if self.tp == 1:
-            return impl(params, cache, tok, done, n_gen, keys, temps, topks,
-                        max_new)
-        from repro.nn.sharding import shard_map_compat
-        rep = P()
-        return shard_map_compat(
-            impl, self._mesh,
-            in_specs=(self._param_specs, self._cache_specs,
-                      rep, rep, rep, rep, rep, rep, rep),
-            out_specs=(self._cache_specs, rep, rep, rep, rep),
-        )(params, cache, tok, done, n_gen, keys, temps, topks, max_new)
+        with jax.named_scope("serve_decode_chunk"):
+            if self.tp == 1:
+                return impl(params, cache, tok, done, n_gen, keys, temps,
+                            topks, max_new)
+            from repro.nn.sharding import shard_map_compat
+            rep = P()
+            return shard_map_compat(
+                impl, self._mesh,
+                in_specs=(self._param_specs, self._cache_specs,
+                          rep, rep, rep, rep, rep, rep, rep),
+                out_specs=(self._cache_specs, rep, rep, rep, rep),
+            )(params, cache, tok, done, n_gen, keys, temps, topks, max_new)
 
     def _seed_kv_scales(self, small, slot: int):
         """Copy the target slot's static KV scale leaves into the batch-1
@@ -592,16 +664,23 @@ class ServeEngine:
         big_flat, treedef = jax.tree_util.tree_flatten(big)
         small_flat = jax.tree_util.tree_flatten(small)[0]
         out = []
-        for b, s, ax in zip(big_flat, small_flat, self._cache_log_flat):
-            if ax == ():
-                out.append(b.at[slot].set(
-                    jnp.ravel(jnp.asarray(s))[0].astype(b.dtype)))
-            else:
-                axis = ax.index("batch")
-                upd = jax.lax.index_in_dim(s, 0, axis=axis, keepdims=False)
-                out.append(jax.lax.dynamic_update_index_in_dim(
-                    b, upd.astype(b.dtype), slot, axis))
+        with jax.named_scope("serve_scatter"):
+            for b, s, ax in zip(big_flat, small_flat, self._cache_log_flat):
+                if ax == ():
+                    out.append(b.at[slot].set(
+                        jnp.ravel(jnp.asarray(s))[0].astype(b.dtype)))
+                else:
+                    axis = ax.index("batch")
+                    upd = jax.lax.index_in_dim(s, 0, axis=axis,
+                                               keepdims=False)
+                    out.append(jax.lax.dynamic_update_index_in_dim(
+                        b, upd.astype(b.dtype), slot, axis))
         return jax.tree_util.tree_unflatten(treedef, out)
+
+    @staticmethod
+    def _sample_impl(logits, keys, temps, topks):
+        with jax.named_scope("serve_sample"):
+            return sample_tokens(logits, keys, temps, topks)
 
     def _chunk_impl(self, params, cache, tok, done, n_gen, keys, temps,
                     topks, max_new, *, steps: int, eos: int, pad: int,
@@ -655,48 +734,60 @@ class ServeEngine:
         live = self._slot_rid >= 0
         if not live.any():
             return []
-        n_gen = np.zeros(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        topks = np.zeros(B, np.int32)
-        max_new = np.full(B, np.iinfo(np.int32).max, np.int32)
-        for b, rid in enumerate(self._slot_rid):
-            if rid < 0:
-                continue
-            st = self._states[rid]
-            n_gen[b] = len(st.out)
-            temps[b] = st.req.sampling.temperature
-            topks[b] = st.req.sampling.top_k
-            max_new[b] = self._eff_max_new(st)
-        eos = self.eos_id if self.eos_id is not None else -1
-        rem = int((max_new[live] - n_gen[live]).max())
-        steps = min(steps, 1 << max(rem - 1, 0).bit_length())
+        self.n_chunks += 1
+        with self._spans.step("serve.step", self.n_chunks - 1,
+                              live=int(live.sum())) as chunk:
+            with self._spans.span("serve.step.inputs"):
+                n_gen = np.zeros(B, np.int32)
+                temps = np.zeros(B, np.float32)
+                topks = np.zeros(B, np.int32)
+                max_new = np.full(B, np.iinfo(np.int32).max, np.int32)
+                for b, rid in enumerate(self._slot_rid):
+                    if rid < 0:
+                        continue
+                    st = self._states[rid]
+                    n_gen[b] = len(st.out)
+                    temps[b] = st.req.sampling.temperature
+                    topks[b] = st.req.sampling.top_k
+                    max_new[b] = self._eff_max_new(st)
+                eos = self.eos_id if self.eos_id is not None else -1
+                rem = int((max_new[live] - n_gen[live]).max())
+                steps = min(steps, 1 << max(rem - 1, 0).bit_length())
+                if self.paged:
+                    # allocate page coverage for every live slot's
+                    # worst-case chunk advance BEFORE the scan runs
+                    # device-side (allocation is host state; a mid-chunk
+                    # page-boundary crossing cannot call out)
+                    for b, rid in enumerate(self._slot_rid):
+                        if rid < 0:
+                            continue
+                        row = self._pager.ensure(
+                            rid, min(int(self._slot_pos[b]) + steps,
+                                     self.max_len))
+                        if row is not None:
+                            self.cache["pages"] = self.cache["pages"].at[
+                                b].set(jnp.asarray(row))
+            chunk.annotation.set_metadata(steps=steps)
+            with self._spans.span("serve.step.launch"):
+                self.cache, self._tok, _, _, toks = self._chunk_fn(
+                    self.params, self.cache, self._tok, jnp.asarray(~live),
+                    jnp.asarray(n_gen), self._keys, jnp.asarray(temps),
+                    jnp.asarray(topks), jnp.asarray(max_new),
+                    steps=steps, eos=int(eos), pad=self.pad_id,
+                    greedy_only=bool((temps == 0).all()),
+                    topk_any=bool((topks > 0).any()))
+            with self._spans.span("serve.step.read"):
+                toks = np.asarray(toks)          # blocks; (steps, B)
+            self.decode_steps += steps
+            self.clock += steps
+            with self._spans.span("serve.step.emit"):
+                return self._emit(toks, steps, n_gen)
 
-        if self.paged:
-            # allocate page coverage for every live slot's worst-case chunk
-            # advance BEFORE the scan runs device-side (allocation is host
-            # state; a mid-chunk page-boundary crossing cannot call out)
-            for b, rid in enumerate(self._slot_rid):
-                if rid < 0:
-                    continue
-                row = self._pager.ensure(
-                    rid, min(int(self._slot_pos[b]) + steps, self.max_len))
-                if row is not None:
-                    self.cache["pages"] = self.cache["pages"].at[b].set(
-                        jnp.asarray(row))
-
-        t0 = time.perf_counter()
-        self.cache, self._tok, _, _, toks = self._chunk_fn(
-            self.params, self.cache, self._tok, jnp.asarray(~live),
-            jnp.asarray(n_gen), self._keys, jnp.asarray(temps),
-            jnp.asarray(topks), jnp.asarray(max_new),
-            steps=steps, eos=int(eos), pad=self.pad_id,
-            greedy_only=bool((temps == 0).all()),
-            topk_any=bool((topks > 0).any()))
-        toks = np.asarray(toks)                  # blocks; (steps, B)
-        self.decode_time += time.perf_counter() - t0
-        self.decode_steps += steps
-        self.clock += steps
-
+    def _emit(self, toks: np.ndarray, steps: int, n_gen: np.ndarray
+              ) -> List[RequestState]:
+        """Hand each live slot's chunk tokens to its request up to its
+        stop, retire finished requests, and count the slot-steps that
+        decoded a token and the context each attended."""
         finished: List[RequestState] = []
         for b, rid in enumerate(self._slot_rid):
             if rid < 0:
@@ -717,6 +808,13 @@ class ServeEngine:
                 if len(st.out) >= limit:
                     self._finish(rid, "length")
                     break
+            # the chunk's first step wrote the KV of the slot's last token,
+            # at position len(prompt) + n_gen - 1, and attended one more
+            n = len(st.out) - int(n_gen[b])
+            pos = int(np.asarray(st.req.prompt).size) + int(n_gen[b]) - 1
+            self.generated_tokens += n
+            self.live_slot_steps += n
+            self.live_context_sum += n * pos + n * (n + 1) // 2
             if st.done:
                 finished.append(st)
         return finished
@@ -731,39 +829,46 @@ class ServeEngine:
         """
         for r in sorted(requests, key=lambda r: (r.arrival, r.rid)):
             self.submit(r)
-        t0 = time.perf_counter()
-        while self._pending or self.active_rids:
-            self.admit_ready()
-            if not self.active_rids:
-                nxt = min(self._states[rid].req.arrival
-                          for rid in self._pending)
-                self.clock = max(self.clock, nxt)
-                continue
-            self.step(chunk)
-        self.total_time = time.perf_counter() - t0
+        with self._spans.span("serve.run"):
+            while self._pending or self.active_rids:
+                self.admit_ready()
+                if not self.active_rids:
+                    nxt = min(self._states[rid].req.arrival
+                              for rid in self._pending)
+                    self.clock = max(self.clock, nxt)
+                    continue
+                self.step(chunk)
         done, self._done_box = self._done_box, []
         return sorted(done, key=lambda s: s.req.rid)
 
     # -- reporting -----------------------------------------------------------
 
     def stats(self) -> Dict[str, Any]:
-        gen = sum(len(s.out) for s in self._states.values())
+        """Running totals; O(1) in the requests served and the size of the
+        prefix index. ``spans`` maps each span name to its count and host
+        seconds (``launch/tracing.py``)."""
         # one token per *admission* comes from prefill logits (so one per
         # request plus one per eviction/resume); the rest are decode steps
-        n_dec = gen - self.n_prefill_sampled
         out = {
             "requests": len(self._states),
-            "generated_tokens": gen,
+            "generated_tokens": self.generated_tokens,
             "prefill_sampled_tokens": self.n_prefill_sampled,
-            "decode_tokens": n_dec,
+            "decode_tokens": self.generated_tokens - self.n_prefill_sampled,
             "decode_steps": self.decode_steps,
             "prefill_time_s": self.prefill_time,
             "decode_time_s": self.decode_time,
-            "decode_tok_per_s": n_dec / self.decode_time
-            if self.decode_time else 0.0,
+            "live_slot_steps": self.live_slot_steps,
+            "live_context_sum": self.live_context_sum,
+            "admissions": self.admissions,
+            "admitted_prompt_tokens": self.admitted_prompt_tokens,
+            "prefill_tokens": self.prefill_tokens,
+            "programs": {name: fn._cache_size()
+                         for name, fn in self._programs.items()},
+            "spans": self._spans.report(),
         }
         if self.paged:
             # prefix_hit_tokens = prefill tokens skipped via shared pages;
             # resident_pages counts live pool pages (slots + index)
             out.update(self._pager.stats())
         return out
+

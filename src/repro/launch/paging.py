@@ -134,6 +134,9 @@ class RadixPrefixIndex:
         self._root = _Node(pid=-1)
         self._clock = 0
         self.n_holdings = 0
+        # distinct tokens in indexed pages, kept as holdings come and go
+        # (``resident_tokens`` recounts them by walking the tree)
+        self.n_resident_tokens = 0
 
     def _tick(self) -> int:
         self._clock += 1
@@ -197,6 +200,7 @@ class RadixPrefixIndex:
                 self.alloc.incref(child.pid)
                 node.children[key] = child
                 self.n_holdings += 1
+                self.n_resident_tokens += ps
                 added += 1
             node = child
             node.last_used = self._tick()
@@ -208,6 +212,8 @@ class RadixPrefixIndex:
                 self.alloc.incref(int(pids[i]))
                 node.tail = (rem, int(pids[i]))
                 self.n_holdings += 1 - (0 if old is None else 1)
+                self.n_resident_tokens += len(rem) - (
+                    0 if old is None else len(old[0]))
                 if old is not None:
                     self.alloc.decref(old[1])
                 added += 1
@@ -216,7 +222,8 @@ class RadixPrefixIndex:
     def resident_tokens(self) -> int:
         """Distinct tokens resident in indexed pages (full pages count
         ``page_size``, tails their run length) — page-level dedup is
-        inherent: a shared page appears once in the tree."""
+        inherent: a shared page appears once in the tree. Walks the tree;
+        ``n_resident_tokens`` is the same count kept up to date."""
         total = 0
         stack = [self._root]
         while stack:
@@ -257,10 +264,12 @@ class RadixPrefixIndex:
             progressed = False
             for _, parent, key in cands:
                 if key is None:
-                    _, pid = parent.tail
+                    run, pid = parent.tail
                     parent.tail = None
+                    self.n_resident_tokens -= len(run)
                 else:
                     pid = parent.children.pop(key).pid
+                    self.n_resident_tokens -= self.page_size
                 self.n_holdings -= 1
                 progressed = True
                 if self.alloc.decref(pid):
@@ -450,6 +459,7 @@ class PagedKVManager:
         return list(self._seqs[rid].pids)
 
     def stats(self) -> Dict[str, float]:
+        """Counters only: O(1) in the sequences and the index size."""
         return {
             "resident_pages": self.alloc.n_resident,
             "free_pages": self.alloc.n_free,
@@ -462,7 +472,7 @@ class PagedKVManager:
             "pages_reclaimed": self.pages_reclaimed,
             "cow_copies": self.cow_copies,
             "index_holdings": self.index.n_holdings,
-            "index_resident_tokens": self.index.resident_tokens(),
+            "index_resident_tokens": self.index.n_resident_tokens,
         }
 
     def check(self) -> None:
@@ -492,3 +502,8 @@ class PagedKVManager:
         live = set(np.nonzero(self.alloc._ref > 0)[0].tolist())
         if free & live:
             raise AssertionError(f"pages both free and referenced: {free & live}")
+        walked = self.index.resident_tokens()
+        if walked != self.index.n_resident_tokens:
+            raise AssertionError(
+                f"index_resident_tokens drift: kept "
+                f"{self.index.n_resident_tokens}, walk finds {walked}")

@@ -263,19 +263,24 @@ def main(argv=None) -> None:
     done = engine.run(requests)
 
     stats = engine.stats()
-    n_prefill_tok = sum(len(s.context) for s in done)
+    spans = stats["spans"]
+    n_prefill_tok = stats["prefill_tokens"]
     n_gen = stats["generated_tokens"]
     n_dec = stats["decode_tokens"]      # excludes prefill-sampled tokens
     print(f"served {len(done)} requests on {args.batch} slots "
           f"(chunk={args.chunk}, arrival gap={args.arrival_gap} steps)")
-    print(f"prefill: {n_prefill_tok} prompt tokens in "
+    print(f"prefill: {n_prefill_tok} tokens prefilled (of "
+          f"{stats['admitted_prompt_tokens']} admitted) in "
           f"{engine.prefill_time:.3f}s ({n_prefill_tok/engine.prefill_time:.0f}"
           f" tok/s, +{stats['prefill_sampled_tokens']} sampled tokens)")
     print(f"decode:  {engine.decode_steps} scan steps, {n_dec} tokens in "
           f"{engine.decode_time:.3f}s ({n_dec/max(engine.decode_time,1e-9):.1f}"
           f" tok/s)")
-    print(f"total:   {n_gen} generated tokens in {engine.total_time:.3f}s "
-          f"({n_gen/engine.total_time:.1f} tok/s end-to-end)")
+    total_s = spans["serve.run"]["s"]
+    print(f"total:   {n_gen} generated tokens in {total_s:.3f}s "
+          f"({n_gen/total_s:.1f} tok/s end-to-end)")
+    print("spans:   " + ", ".join(f"{name} {v['n']}x {v['s']:.3f}s"
+                                  for name, v in sorted(spans.items())))
     if args.paged:
         print(f"paging:  page={engine.page_size} tok, "
               f"{stats['resident_pages']}/{engine.n_pages - 1} pages "

@@ -43,9 +43,13 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _kernel_text(fn, *args) -> str:
+def _kernel_text(name, fn, *args) -> str:
+    """The compiled program's text; its kernel call carries the kernel's
+    own name (``pallas_call(name=...)``), which a profile shows."""
     txt = _compile(fn, *args).as_text()
-    assert "tpu_custom_call" in txt
+    calls = [line for line in txt.splitlines() if "tpu_custom_call" in line]
+    assert calls
+    assert all(f"/{name}/pallas_call" in line for line in calls), calls[0]
     return txt
 
 
@@ -63,6 +67,7 @@ def test_pofx_matmul_compiles(one_chip, m):
     from repro.kernels.pofx_matmul import pofx_matmul
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     _kernel_text(
+        "pofx_matmul",
         lambda x, c, s: pofx_matmul(x, c, s, 8, 2, 8,
                                     blocks=DEFAULT_BLOCKS["tpu"],
                                     interpret=False),
@@ -77,6 +82,7 @@ def test_fxp_matmul_compiles(one_chip):
     from repro.kernels.fxp_matmul import fxp_matmul
     S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     _kernel_text(
+        "fxp_matmul",
         lambda a, b: fxp_matmul(a, b, blocks=DEFAULT_BLOCKS["tpu"],
                                 interpret=False),
         S((512, D_MODEL), jnp.int8), S((D_MODEL, D_FF), jnp.int8))
@@ -94,6 +100,7 @@ def test_kv_flash_decode_compiles(one_chip, kind):
     codes = S((B, G, Sl, D_HEAD), kv_code_dtype(spec))
     scale = S((B, G, 1, D_HEAD), jnp.float32)
     _kernel_text(
+        "kv_flash_decode",
         lambda q, kc, ks, vc, vs, pos: kv_flash_decode(
             q, kc, ks, vc, vs, pos, spec, block_s=512, interpret=False),
         S((B, G, R, D_HEAD), jnp.float32), codes, scale, codes, scale,
@@ -110,10 +117,21 @@ def test_kv_flash_paged_decode_compiles(one_chip):
     pool = S((B * max_pages + 1, G, page, D_HEAD), jnp.int8)
     scale = S((G, 1, D_HEAD), jnp.float32)
     _kernel_text(
+        "kv_flash_paged_decode",
         lambda q, kc, ks, vc, vs, tbl, pos: kv_flash_paged_decode(
             q, kc, ks, vc, vs, tbl, pos, spec, interpret=False),
         S((B, G, R, D_HEAD), jnp.float32), pool, scale, pool, scale,
         S((B, max_pages), jnp.int32), S((B,), jnp.int32))
+
+
+def test_pofx_decode_compiles(one_chip):
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.pofx_decode import pofx_decode
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    _kernel_text(
+        "pofx_decode", lambda c: pofx_decode(c, 8, 2, 8, interpret=False),
+        S((D_FF, D_MODEL), jnp.uint8))
 
 
 @pytest.fixture(scope="module")
