@@ -43,7 +43,7 @@ def run(smoke: bool = False):
         x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
         codes = jnp.asarray(rng.integers(0, 128, (k, n)), jnp.int32)
         scale = jnp.asarray(rng.uniform(0.5, 2.0, (n,)), jnp.float32)
-        for mode in ("bitlevel", "onehot"):
+        for mode in ("bitlevel", "table"):
             got = pofx_matmul(x, codes, scale, 8, 2, 8, blocks=(64, 64, 64),
                               decode_mode=mode, interpret=True)
             ref = pofx_matmul_ref(x, codes, scale, 8, 2, 8)

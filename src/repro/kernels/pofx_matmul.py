@@ -4,15 +4,23 @@ This is the paper's PoFx(Move & Store) accelerator (Fig. 20, design 3) mapped
 onto the TPU memory hierarchy:
 
     HBM:   W stored as uint8 normalized-posit codes  ((N-1)/16 of bf16 bytes)
-    VMEM:  per-(k,j) tile decoded on the VPU (bit-level Algorithm 1), then
-    MXU:   bf16/f32 dot against the activation tile, f32 accumulation in a
-           VMEM scratch accumulator across the k grid dimension.
+    VMEM:  per-(k,j) tile decoded on the VPU, then
+    MXU:   dot against the activation tile in the activations' dtype (bf16
+           or f32), f32 accumulation in a VMEM scratch accumulator across
+           the k grid dimension.
 
 Decode modes:
-  "bitlevel" — Algorithm 1 stages as lane-wise int32 ops (faithful port);
-  "onehot"   — 2^(N-1)-entry LUT realized as one-hot @ table matmul, i.e. the
-               decode itself runs on the MXU (TPU-idiomatic alternative; the
-               §Perf log compares both).
+  "table"    — (default) the 2^(N-1)-entry PoFx table (``pofx_norm_lut``,
+               built by Algorithm 1) held in one 128-lane row; each
+               128-lane slab of the code tile is a lane gather from it.
+               Needs 2^(N-1) <= 128, i.e. N <= 8; wider formats take
+               "bitlevel".
+  "bitlevel" — Algorithm 1 stages as lane-wise int32 ops on every weight
+               (the faithful port, the oracle's twin).
+
+Both give the same FxP(M, M-1) value for every code. For M <= 8 each value
+/ 2^(M-1) is exact in bf16, so a bf16 x meets the MXU against a bf16 weight
+tile with every product exact; any other x, or a wider M, runs in f32.
 
 Weight HBM traffic per step drops to (N-1 bits)/weight vs 16 (bf16) — this is
 the paper's storage/communication reduction re-expressed as the memory-
@@ -24,6 +32,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.pofx import pofx_norm_lut
@@ -32,41 +41,63 @@ from .ref import decode_norm_to_fxp
 
 __all__ = ["pofx_matmul"]
 
+_DECODE_MODES = ("table", "bitlevel")
+_LANES = 128  # one vreg row: the table's width and the gather's slab width
 
-def _kernel(x_ref, w_ref, s_ref, lut_ref, o_ref, acc_ref, *, N, ES, M, nk, decode_mode):
+
+def _kernel(*refs, N, ES, M, nk, table):
+    if table:
+        x_ref, w_ref, s_ref, t_ref, o_ref, acc_ref = refs
+    else:
+        x_ref, w_ref, s_ref, o_ref, acc_ref = refs
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = w_ref[...].astype(jnp.int32)
-    inv = 1.0 / (1 << (M - 1))
-    if decode_mode == "onehot":
-        # One-hot matmul against the LUT: decode on the MXU. codes tile
-        # (bk, bn) -> one-hot against the 2^(N-1)-entry value table.
-        depth = 1 << (N - 1)
-        oh = (codes[..., None] == jax.lax.broadcasted_iota(jnp.int32, (1, 1, depth), 2))
-        vals = lut_ref[...].astype(jnp.float32) * inv  # (1, depth)
-        w = jnp.sum(oh.astype(jnp.float32) * vals[0], axis=-1)
+    # |FxP(M, M-1)| < 2^7 for M <= 8, so the weight tile is exact in bf16.
+    dt = jnp.bfloat16 if x_ref.dtype == jnp.bfloat16 and M <= 8 else jnp.float32
+    x = x_ref[...].astype(dt)
+    bk, bn = w_ref.shape
+    if table:
+        # A gather indexes within one 128-lane row, so decode slab by slab.
+        tbl = jnp.broadcast_to(t_ref[...], (bk, _LANES))
+        for j in range(0, bn, _LANES):
+            sl = slice(j, min(j + _LANES, bn))
+            codes = w_ref[:, sl].astype(jnp.int32)
+            width = codes.shape[1]
+            if width < _LANES:  # a narrow last slab: gather a full row
+                codes = jnp.pad(codes, ((0, 0), (0, _LANES - width)))
+            w = jnp.take_along_axis(tbl, codes, axis=1,
+                                    mode="promise_in_bounds")[:, :width]
+            acc_ref[:, sl] += jnp.dot(x, w.astype(dt),
+                                      preferred_element_type=jnp.float32)
     else:
-        fxp = decode_norm_to_fxp(codes, N, ES, M)
-        w = fxp.astype(jnp.float32) * inv
-    acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
-                            preferred_element_type=jnp.float32)
+        fxp = decode_norm_to_fxp(w_ref[...].astype(jnp.int32), N, ES, M)
+        w = (fxp.astype(jnp.float32) * (1.0 / (1 << (M - 1)))).astype(dt)
+        acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _done():
         o_ref[...] = (acc_ref[...] * s_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
+def _table(N: int, ES: int, M: int) -> jax.Array:
+    """The decoded values of every code, / 2^(M-1), padded to 128 lanes."""
+    vals = pofx_norm_lut(N, ES, M).astype(np.float32) / (1 << (M - 1))
+    return jnp.asarray(np.pad(vals, (0, _LANES - vals.size)).reshape(1, _LANES))
+
+
 @functools.partial(jax.jit, static_argnames=("N", "ES", "M", "blocks", "decode_mode",
                                              "interpret", "out_dtype"))
 def pofx_matmul(x: jax.Array, codes: jax.Array, scale: jax.Array,
                 N: int, ES: int, M: int = 8, blocks=None,
-                decode_mode: str = "bitlevel", interpret: bool | None = None,
+                decode_mode: str = "table", interpret: bool | None = None,
                 out_dtype=jnp.float32) -> jax.Array:
     """x:(m,k) @ decode(codes:(k,n)) * scale:(n,) -> (m,n)."""
+    if decode_mode not in _DECODE_MODES:
+        raise ValueError(f"decode_mode {decode_mode!r} not in {_DECODE_MODES}")
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     if blocks is None:
@@ -84,22 +115,24 @@ def pofx_matmul(x: jax.Array, codes: jax.Array, scale: jax.Array,
     cp = jnp.pad(codes, ((0, pk), (0, pn)))  # code 0 decodes to 0 -> safe pad
     sp = jnp.pad(jnp.reshape(scale, (1, -1)).astype(jnp.float32), ((0, 0), (0, pn)))
     grid = (xp.shape[0] // bm, cp.shape[1] // bn, xp.shape[1] // bk)
-    depth = 1 << (N - 1)
-    lut = jnp.asarray(pofx_norm_lut(N, ES, M), jnp.int32).reshape(1, depth)
+    table = decode_mode == "table" and (1 << (N - 1)) <= _LANES
+    in_specs = [
+        pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+        pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+        pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+    ]
+    operands = [xp, cp, sp]
+    if table:
+        in_specs.append(pl.BlockSpec((1, _LANES), lambda i, j, k: (0, 0)))
+        operands.append(_table(N, ES, M))
     out = pl.pallas_call(
-        functools.partial(_kernel, N=N, ES=ES, M=M, nk=grid[2],
-                          decode_mode=decode_mode),
+        functools.partial(_kernel, N=N, ES=ES, M=M, nk=grid[2], table=table),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-            pl.BlockSpec((1, depth), lambda i, j, k: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], cp.shape[1]), out_dtype),
         scratch_shapes=[vmem_scratch((bm, bn))],
         interpret=interpret,
         name="pofx_matmul",
-    )(xp, cp, sp, lut)
+    )(*operands)
     return out[:m, :n]

@@ -59,8 +59,11 @@ def _kv_spec(kind):
             else QuantSpec(kind="pofx", N=8, ES=2, M=8))
 
 
-@pytest.mark.parametrize("m", [8, 512])
-def test_pofx_matmul_compiles(one_chip, m):
+@pytest.mark.parametrize("m,x_dtype", [(8, "bfloat16"), (24, "bfloat16"),
+                                       (512, "bfloat16"), (24, "float32")])
+def test_pofx_matmul_compiles(one_chip, m, x_dtype):
+    """The table decode (the default) at the cells' 24 live decode rows, a
+    small batch and a prefill block; bf16 x feeds the MXU bf16, f32 x f32."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import DEFAULT_BLOCKS
@@ -71,7 +74,7 @@ def test_pofx_matmul_compiles(one_chip, m):
         lambda x, c, s: pofx_matmul(x, c, s, 8, 2, 8,
                                     blocks=DEFAULT_BLOCKS["tpu"],
                                     interpret=False),
-        S((m, D_FF), jnp.bfloat16), S((D_FF, D_MODEL), jnp.uint8),
+        S((m, D_FF), jnp.dtype(x_dtype)), S((D_FF, D_MODEL), jnp.uint8),
         S((D_MODEL,), jnp.float32))
 
 

@@ -44,7 +44,7 @@ def test_pofx_decode_kernel_all_codes(N, ES):
 
 
 @pytest.mark.parametrize("m,k,n", MM_SHAPES)
-@pytest.mark.parametrize("mode", ["bitlevel", "onehot"])
+@pytest.mark.parametrize("mode", ["bitlevel", "table"])
 def test_pofx_matmul_kernel(m, k, n, mode):
     x = jnp.asarray(RNG.standard_normal((m, k)).astype(np.float32))
     codes = jnp.asarray(RNG.integers(0, 128, size=(k, n)), dtype=jnp.uint8)
@@ -52,6 +52,60 @@ def test_pofx_matmul_kernel(m, k, n, mode):
     y = pofx_matmul(x, codes, scale, 8, 2, 8, blocks=(32, 128, 64), decode_mode=mode)
     ref = pofx_matmul_ref(x, codes, scale, 8, 2, 8)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("N,ES", POSIT_CONFIGS)
+@pytest.mark.parametrize("mode", ["bitlevel", "table"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pofx_matmul_every_code_exact(N, ES, mode, dtype):
+    """identity @ codes returns each code's decoded value bit for bit, for
+    every code, under both decode modes and both MXU operand dtypes ((9, 2)
+    has 256 codes, more than the table's 128 lanes, and takes bitlevel)."""
+    M = 8
+    all_codes = np.resize(np.arange(1 << (N - 1)), (128, 384))
+    codes = jnp.asarray(all_codes, jnp.uint8)
+    scale = jnp.asarray(np.exp2(RNG.integers(-3, 4, 384)), jnp.float32)
+    y = pofx_matmul(jnp.eye(128, dtype=dtype), codes, scale, N, ES, M,
+                    blocks=(64, 256, 128), decode_mode=mode)
+    fxp = decode_norm_to_fxp(jnp.asarray(all_codes, jnp.int32), N, ES, M)
+    want = fxp.astype(jnp.float32) / (1 << (M - 1)) * scale
+    assert np.array_equal(np.asarray(y), np.asarray(want))
+
+
+@pytest.mark.parametrize("M", [8, 12])
+def test_pofx_matmul_bf16_x_exact_products(M):
+    """A bf16 x meets a bf16 weight tile only where every FxP(M, M-1) value
+    is exact in bf16 (M <= 8); wider M keeps f32. Either way every product
+    equals the f32 oracle's, so the result differs only by accumulation
+    order: within 1e-6 of the sum of the products' magnitudes."""
+    m, k, n = 24, 320, 256
+    x = jnp.asarray(RNG.standard_normal((m, k)), jnp.bfloat16)
+    codes = jnp.asarray(RNG.integers(0, 128, size=(k, n)), jnp.uint8)
+    scale = jnp.asarray((np.abs(RNG.standard_normal(n)) + 0.1).astype(np.float32))
+    y = pofx_matmul(x, codes, scale, 8, 2, M, blocks=(32, 128, 64))
+    xf = x.astype(jnp.float32)
+    ref = np.asarray(pofx_matmul_ref(xf, codes, scale, 8, 2, M))
+    w = decode_norm_to_fxp(codes.astype(jnp.int32), 8, 2, M) / (1 << (M - 1))
+    mag = np.asarray(jnp.abs(xf) @ jnp.abs(w) * scale)
+    assert np.all(np.abs(np.asarray(y) - ref) <= 1e-6 * mag)
+
+
+@pytest.mark.parametrize("N,ES", POSIT_CONFIGS[:5])
+def test_pofx_matmul_table_code_zero_is_zero(N, ES):
+    """The table's entry for code 0 is 0, as the zero padding of ragged
+    K and N tiles assumes: an all-zero code matrix gives an exact zero."""
+    x = jnp.asarray(RNG.standard_normal((5, 70)), jnp.float32)
+    codes = jnp.zeros((70, 130), jnp.uint8)
+    y = pofx_matmul(x, codes, jnp.ones((130,), jnp.float32), N, ES, 8,
+                    blocks=(32, 128, 64), decode_mode="table")
+    assert np.array_equal(np.asarray(y), np.zeros((5, 130), np.float32))
+
+
+def test_pofx_matmul_rejects_unknown_decode_mode():
+    x = jnp.ones((8, 64), jnp.float32)
+    codes = jnp.zeros((64, 128), jnp.uint8)
+    with pytest.raises(ValueError, match="decode_mode"):
+        pofx_matmul(x, codes, jnp.ones((128,)), 8, 2, 8, decode_mode="onehot")
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
