@@ -4,9 +4,13 @@
 
 Layout, all found by name:
 
-- ``configs/<config>.json``: a model configuration as it is run: sizes,
-  weight and KV formats, engine options, the reference it is checked
-  against, and the limit of that check;
+- ``configs/<config>.json``: a model configuration as it is run: its
+  ``family`` (dense when absent) and published keys, which the table in
+  ``cell.py`` maps to the system's ``ModelConfig`` (a key it cannot place
+  is refused; ``"published"`` holds published keys no field holds yet,
+  ``"system"`` fields no published key names), weight and KV formats and
+  engine options (``serving``, with ``tp`` the chips a model is sharded
+  over), the reference it is checked against, and the limit of that check;
 - ``traffic/<mix>.json``: a traffic mix, read by ``generator.py``;
 - ``metrics/<metric>.py``: the reader of one per-layer metric;
 - ``work/<kernel>.py``: FLOPs and bytes of one kernel's calls;

@@ -41,7 +41,8 @@ class Record:
 
 
 def build(cell: Cell, seed: int):
-    """(model, params, engine) of the cell's configuration."""
+    """(model, params) of the cell's configuration, sharded over
+    ``serving.tp`` chips (1 when the file does not say)."""
     import jax
     from repro.core.policy import QuantPolicy, parse_kv_spec
     from repro.launch.serve import build_served_model
@@ -49,7 +50,7 @@ def build(cell: Cell, seed: int):
     model, params = build_served_model(
         model_config(cell.config), QuantPolicy.from_string(sv["weights"]),
         parse_kv_spec(sv["kv_cache"]), use_kernel=bool(sv["kernels"]),
-        seed=seed & 0xFFFFFFFF)
+        tp=sv.get("tp", 1), seed=seed & 0xFFFFFFFF)
     jax.block_until_ready(params)
     return model, params
 

@@ -18,7 +18,13 @@ def work(heads: int, kv_heads: int, head_dim: int,
 
 
 def decode_step_work(config: dict, lengths: Sequence[int]) -> tuple:
-    from bench.work.model import dims
+    """Every attention layer's call; a windowed layer's slots attend to
+    min(length, window) positions."""
+    from bench.work.model import attended, attention_layers, dims
     m = dims(config)
-    f, b = work(m["H"], m["G"], m["Dh"], lengths)
-    return m["L"] * f, m["L"] * b
+    f = b = 0.0
+    for layers, window in attention_layers(config):
+        lf, lb = work(m["H"], m["G"], m["Dh"],
+                      [attended(n, window) for n in lengths])
+        f, b = f + layers * lf, b + layers * lb
+    return f, b

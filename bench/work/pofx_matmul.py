@@ -11,10 +11,15 @@ def work(m: int, k: int, n: int) -> tuple:
 
 
 def decode_step_work(config: dict, live_rows: int) -> tuple:
-    """(FLOPs, bytes) of the calls one decode step makes."""
-    from bench.work.model import decode_matmuls
+    """(FLOPs, bytes) of the weight matmuls one decode step makes in this
+    kernel over ``live_rows`` rows: those of
+    ``bench/work/model.py::step_matmuls`` the system runs through it
+    (``Matmul.kernel``), each once; the router, the routed experts and
+    mamba1's x and dt projections run elsewhere."""
+    from bench.work.model import step_matmuls
     f = b = 0.0
-    for k, n in decode_matmuls(config):
-        df, db = work(live_rows, k, n)
-        f, b = f + df, b + db
+    for mm in step_matmuls(config):
+        if mm.kernel:
+            df, db = work(live_rows, mm.k, mm.n)
+            f, b = f + df, b + db
     return f, b

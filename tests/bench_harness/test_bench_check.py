@@ -87,7 +87,7 @@ def _tiny_cell(paged: bool):
                   "unique": {"median": 24, "sigma": 0.5, "min": 8,
                              "max": 60}}
     else:
-        arrivals = {"process": "backlog", "per_slot": 8}
+        arrivals = {"process": "backlog", "per_slot": 32}
         prompt = {"unique": {"median": 24, "sigma": 0.5, "min": 8,
                              "max": 60}}
     mix = {"arrivals": arrivals, "prompt": prompt,
